@@ -233,8 +233,11 @@ class ExecutorWorker(threading.Thread):
             except BaseException as e:  # noqa: BLE001 — forwarded to client
                 exec_time = time.perf_counter() - t0
                 self.on_done(self, inv, exec_time, e)
-                inv.future._fail(e if isinstance(e, ExecutorCrash)
-                                 else ExecutorCrash(repr(e)))
+                if not isinstance(e, ExecutorCrash):
+                    crash = ExecutorCrash(repr(e))
+                    crash.__cause__ = e       # the client sees the original
+                    e = crash
+                inv.future._fail(e)
                 if not self.alive_flag:
                     # mirror virtual-mode _fail_pending: queued work
                     # behind the crash fails now, not at its timeout

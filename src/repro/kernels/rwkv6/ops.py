@@ -16,10 +16,11 @@ from repro.kernels.rwkv6.ref import wkv6_ref
 
 
 def _pad_time(t, chunk, value=0.0):
-    s = t.shape[1]
+    """Pad the time axis (2 of (b, H, s, K)) to a multiple of ``chunk``."""
+    s = t.shape[2]
     pad = (-s) % chunk
     if pad:
-        t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2),
+        t = jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)),
                     constant_values=value)
     return t, s
 
@@ -33,7 +34,7 @@ def wkv6_chunked(r, k, v, w, u, state, *, chunk=128):
     (S = 1·S + 0), not erase it (S = 0·S + 0)."""
     (r, s0), (k, _), (v, _) = (_pad_time(t, chunk) for t in (r, k, v))
     w, _ = _pad_time(w, chunk, value=1.0)
-    b, s, H, K = r.shape
+    b, H, s, K = r.shape
     nb = s // chunk
 
     @functools.partial(jax.checkpoint, prevent_cse=False)
@@ -41,14 +42,16 @@ def wkv6_chunked(r, k, v, w, u, state, *, chunk=128):
         y, S = wkv6_ref(ts[0], ts[1], ts[2], ts[3], u, S)
         return S, y
 
-    xs = tuple(t.reshape(b, nb, chunk, H, -1).swapaxes(0, 1)
+    xs = tuple(jnp.moveaxis(t.reshape(b, H, nb, chunk, -1), 2, 0)
                for t in (r, k, v, w))
     S, ys = jax.lax.scan(body, state.astype(jnp.float32), xs)
-    y = ys.swapaxes(0, 1).reshape(b, s, H, -1)[:, :s0]
+    y = jnp.moveaxis(ys, 0, 2).reshape(b, H, s, -1)[:, :, :s0]
     return y.astype(r.dtype), S
 
 
 def wkv6(r, k, v, w, u, state, *, chunk=128, use_pallas=None):
+    """r/k/v/w (b, H, s, K), heads-major; u (H, K); state (b, H, K, V).
+    Returns (y (b, H, s, V), final state f32)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:

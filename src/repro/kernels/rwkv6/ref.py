@@ -3,7 +3,8 @@
     S_t = diag(w_t)·S_{t-1} + k_tᵀ⊗v_t
     y_t = r_t·(S_{t-1} + diag(u)·k_tᵀ⊗v_t)
 
-Shapes: r,k,v,w (b, s, H, K[=V]); u (H, K); state (b, H, K, V).
+Shapes: r,k,v,w (b, H, s, K[=V]), heads-major; u (H, K); state
+(b, H, K, V).
 w is the *decay* already mapped to (0,1) = exp(-exp(·)).
 """
 from __future__ import annotations
@@ -13,8 +14,6 @@ import jax.numpy as jnp
 
 
 def wkv6_ref(r, k, v, w, u, state):
-    b, s, H, K = r.shape
-    V = v.shape[-1]
     rf, kf, vf, wf = (t.astype(jnp.float32) for t in (r, k, v, w))
     uf = u.astype(jnp.float32)
 
@@ -26,7 +25,7 @@ def wkv6_ref(r, k, v, w, u, state):
         S = wt[..., :, None] * S + outer
         return S, y
 
-    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (rf, kf, vf, wf))
+    xs = tuple(jnp.moveaxis(t, 2, 0) for t in (rf, kf, vf, wf))
     S, ys = jax.lax.scan(step, state.astype(jnp.float32), xs)
-    y = jnp.moveaxis(ys, 0, 1)                   # (b,s,H,V)
+    y = jnp.moveaxis(ys, 0, 2)                   # (b,H,s,V)
     return y.astype(r.dtype), S

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.models import layers as L
 

@@ -73,32 +73,33 @@ def time_mix(x, p, cfg, state, last_x):
     mixed = {key: x + xx * (p["mu"][i] + deltas[:, :, i])
              for i, key in enumerate(MIX_KEYS)}
 
-    r = (mixed["r"] @ p["wr"]).reshape(b, s, H, hd)
-    k = (mixed["k"] @ p["wk"]).reshape(b, s, H, hd)
-    v = (mixed["v"] @ p["wv"]).reshape(b, s, H, hd)
-    g = jax.nn.silu(mixed["g"] @ p["wg"])
+    # the head-wise part runs heads-major, (b, H, s, hd): the projections
+    # write that layout and ``wo`` reads it back, so no transpose stands
+    # between them and the WKV kernel
+    heads = lambda t, wt: jnp.einsum("bsd,dhk->bhsk", t,
+                                     wt.reshape(wt.shape[0], H, hd))
+    r, k, v = (heads(mixed[n], p["w" + n]) for n in "rkv")
+    g = jax.nn.silu(heads(mixed["g"], p["wg"]))
 
-    dw = jnp.tanh(mixed["w"] @ p["decay_w1"]) @ p["decay_w2"]
-    w = jnp.exp(-jnp.exp((p["w0"].astype(jnp.float32)
-                          + dw.astype(jnp.float32))))          # (b,s,d)
-    w = w.reshape(b, s, H, hd)
+    dw = heads(jnp.tanh(mixed["w"] @ p["decay_w1"]), p["decay_w2"])
+    w = jnp.exp(-jnp.exp((p["w0"].astype(jnp.float32).reshape(H, 1, hd)
+                          + dw.astype(jnp.float32))))
 
     u = p["u"].reshape(H, hd)
     if s == 1:
-        y, new_state = wkv_ops.wkv6_step(r[:, 0], k[:, 0], v[:, 0], w[:, 0],
-                                         u, state)
-        y = y[:, None]
+        y, new_state = wkv_ops.wkv6_step(r[:, :, 0], k[:, :, 0], v[:, :, 0],
+                                         w[:, :, 0], u, state)
+        y = y[:, :, None]
     else:
         y, new_state = wkv_ops.wkv6(r, k, v, w, u, state)
-    y = y.reshape(b, s, d)
     # per-head group norm
-    yf = y.astype(jnp.float32).reshape(b, s, H, hd)
+    yf = y.astype(jnp.float32)
     mu = yf.mean(-1, keepdims=True)
     var = yf.var(-1, keepdims=True)
     yf = (yf - mu) * jax.lax.rsqrt(var + 64e-5)
-    y = (yf.reshape(b, s, d) * p["ln_scale"].astype(jnp.float32)
+    y = (yf * p["ln_scale"].astype(jnp.float32).reshape(H, 1, hd)
          ).astype(x.dtype)
-    out = (y * g) @ p["wo"]
+    out = jnp.einsum("bhsk,hkd->bsd", y * g, p["wo"].reshape(H, hd, d))
     return out, (new_state, x[:, -1, :])
 
 

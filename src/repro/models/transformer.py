@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import attention as A

@@ -68,10 +68,21 @@ class ModelServer:
         with self._lock:
             cache, length = self._sessions.pop(sid)
         tokens = jnp.asarray(payload["tokens"])
-        logits, cache, length = self._decode_fn(self.params, cache, tokens,
-                                                length)
+        try:
+            logits, new_cache, length = self._decode_fn(self.params, cache,
+                                                        tokens, length)
+        except BaseException:
+            # a step that fails to trace, compile or dispatch has not yet
+            # consumed the donated cache: put the session back, so the
+            # client's retry meets the same error, not a missing session.
+            # A failure while the step runs on the device surfaces at the
+            # host read below; the session then holds the step's outputs,
+            # which carry that error into the retry.
+            with self._lock:
+                self._sessions[sid] = (cache, length)
+            raise
         with self._lock:
-            self._sessions[sid] = (cache, length)
+            self._sessions[sid] = (new_cache, length)
         next_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
         return {"sid": sid, "next_token": next_tok}
 

@@ -35,8 +35,9 @@ import dataclasses
 import jax, jax.numpy as jnp
 from repro.configs import get_smoke
 from repro.distribution.context import make_context
+from repro.launch.mesh import make_mesh
 from repro.models.factory import build_model
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 """
 
 
@@ -101,8 +102,9 @@ def test_cell_compiles_smoke_mesh(arch, shape):
     SMOKE configs (the full 512-device pass is launch.dryrun)."""
     run_sub(f"""
 import jax
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import build_cell
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cell = build_cell("{arch}", "{shape}", mesh, smoke=True)
 with mesh:
     comp = jax.jit(cell.step_fn, in_shardings=cell.in_shardings,
@@ -117,9 +119,10 @@ def test_gpipe_forward_matches_sequential():
     application (bubble only costs time, never correctness)."""
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.training.pipeline import gpipe_forward
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 S, M, mb, d = 4, 6, 2, 16
 key = jax.random.PRNGKey(0)
 W = jax.random.normal(key, (S, d, d)) * 0.3

@@ -82,10 +82,11 @@ def test_flash_attention_property(s, h, hd, causal, bq):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,s,H,hd,chunk", [
     (2, 40, 2, 16, 16), (1, 100, 3, 32, 32), (2, 64, 1, 64, 64),
+    (1, 200, 4, 64, 128),     # chip layout: hd=64, default chunk, padded
 ])
 def test_wkv6_kernel(b, s, H, hd, chunk, dtype):
-    r, k, v = (rand(i, (b, s, H, hd), dtype) for i in (1, 2, 3))
-    w = (jax.nn.sigmoid(rand(4, (b, s, H, hd))) * 0.5 + 0.45).astype(dtype)
+    r, k, v = (rand(i, (b, H, s, hd), dtype) for i in (1, 2, 3))
+    w = (jax.nn.sigmoid(rand(4, (b, H, s, hd))) * 0.5 + 0.45).astype(dtype)
     u = rand(5, (H, hd), dtype)
     s0 = rand(6, (b, H, hd, hd))
     y1, S1 = wkv6_pallas(r, k, v, w, u, s0, chunk=chunk, interpret=True)
@@ -100,8 +101,8 @@ def test_wkv6_chunked_matches_ref():
     """The CPU/dry-run chunked-remat twin is also oracle-exact, including
     non-multiple-of-chunk lengths (decay padded with ONES)."""
     b, s, H, hd = 2, 70, 2, 16
-    r, k, v = (rand(i, (b, s, H, hd)) for i in (1, 2, 3))
-    w = jax.nn.sigmoid(rand(4, (b, s, H, hd))) * 0.5 + 0.45
+    r, k, v = (rand(i, (b, H, s, hd)) for i in (1, 2, 3))
+    w = jax.nn.sigmoid(rand(4, (b, H, s, hd))) * 0.5 + 0.45
     u, s0 = rand(5, (H, hd)), rand(6, (b, H, hd, hd))
     y1, S1 = wkv6_chunked(r, k, v, w, u, s0, chunk=32)
     y2, S2 = wkv6_ref(r, k, v, w, u, s0)
@@ -114,12 +115,12 @@ def test_wkv6_chunked_matches_ref():
 def test_wkv6_step_matches_scan():
     """Single-token decode step == one step of the parallel form."""
     b, H, hd = 2, 2, 16
-    r, k, v = (rand(i, (b, 1, H, hd)) for i in (1, 2, 3))
-    w = jax.nn.sigmoid(rand(4, (b, 1, H, hd))) * 0.5 + 0.45
+    r, k, v = (rand(i, (b, H, 1, hd)) for i in (1, 2, 3))
+    w = jax.nn.sigmoid(rand(4, (b, H, 1, hd))) * 0.5 + 0.45
     u, s0 = rand(5, (H, hd)), rand(6, (b, H, hd, hd))
-    y1, S1 = wkv6_step(r[:, 0], k[:, 0], v[:, 0], w[:, 0], u, s0)
+    y1, S1 = wkv6_step(r[:, :, 0], k[:, :, 0], v[:, :, 0], w[:, :, 0], u, s0)
     y2, S2 = wkv6_ref(r, k, v, w, u, s0)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2[:, 0]),
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2[:, :, 0]),
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(S1), np.asarray(S2),
                                rtol=2e-5, atol=2e-5)
@@ -130,6 +131,7 @@ def test_wkv6_step_matches_scan():
 @pytest.mark.parametrize("b,s,di,N,chunk,bd", [
     (2, 40, 24, 8, 16, 16), (1, 100, 64, 16, 32, 32),
     (2, 33, 48, 4, 16, 48),
+    (1, 150, 256, 16, 128, 128),   # chip layout: lane-aligned blocks, N=16
 ])
 def test_mamba_kernel(b, s, di, N, chunk, bd, dtype):
     x = rand(11, (b, s, di), dtype)

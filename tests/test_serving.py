@@ -2,11 +2,17 @@
 residency, straggler backups, fault recovery."""
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import jax
+import pytest
 
 from repro.configs import get_smoke
 from repro.core import (BatchSystem, Invoker, Ledger, ResourceManager)
+from repro.core.executor import ExecutorCrash
 from repro.models.factory import build_model
 from repro.serving import ModelServer, ServeEngine
 from repro.serving.engine import backup_submit
@@ -117,3 +123,96 @@ def test_serving_survives_worker_crash():
     done = engine.run()
     assert len(done) == 3
     inv.deallocate()
+
+
+def test_failed_decode_keeps_session_and_cause():
+    """A decode step that raises leaves its session in place, so the
+    client's retries meet the same error (not a missing session), and the
+    original error reaches the client as the cause of ExecutorCrash."""
+    cfg, server, inv, _ = make_llm_stack()
+    out = inv.invoke("prefill", {"tokens": np.ones((2, 4), np.int32)})
+    sid, nxt = out["sid"], out["next_token"][:, None]
+    step = server._decode_fn
+
+    def lost_device(*args):
+        raise RuntimeError("device lost")
+
+    server._decode_fn = lost_device
+    with pytest.raises(ExecutorCrash) as info:
+        inv.invoke("decode", {"sid": sid, "tokens": nxt})
+    assert isinstance(info.value.__cause__, RuntimeError)
+    assert "device lost" in str(info.value.__cause__)
+    assert sid in server._sessions
+    server._decode_fn = step
+    res = inv.invoke("decode", {"sid": sid, "tokens": nxt})
+    assert res["next_token"].shape == (2,)
+    inv.deallocate()
+
+
+class _FailedOnDevice:
+    """Stands for an output buffer of a step that failed while it ran:
+    reading it, or passing it to the next step, raises the device's
+    error."""
+
+    def __getitem__(self, _):
+        raise RuntimeError("device lost")
+
+
+def test_decode_failing_on_device_reports_device_error():
+    """A step that dispatches but fails on the device raises at the host
+    read, after the donated cache was replaced by the step's outputs.
+    The retries then meet the same device error, never a KeyError."""
+    cfg, server, inv, _ = make_llm_stack()
+    out = inv.invoke("prefill", {"tokens": np.ones((2, 4), np.int32)})
+    sid, nxt = out["sid"], out["next_token"][:, None]
+    calls = []
+
+    def failing_step(params, cache, tokens, length):
+        calls.append(cache)
+        if isinstance(cache, _FailedOnDevice):
+            raise RuntimeError("device lost")
+        return _FailedOnDevice(), _FailedOnDevice(), length
+
+    server._decode_fn = failing_step
+    with pytest.raises(ExecutorCrash) as info:
+        inv.invoke("decode", {"sid": sid, "tokens": nxt})
+    assert len(calls) > 1, "the client did not retry"
+    assert isinstance(info.value.__cause__, RuntimeError)
+    assert "device lost" in str(info.value.__cause__)
+    assert sid in server._sessions
+    inv.deallocate()
+
+
+def test_serve_launcher_smoke():
+    """The launcher's path at a reduced width: warm-up wave, timed waves,
+    every token in the vocabulary."""
+    from repro.launch.serve import serve
+    cfg = get_smoke("h2o-danube-3-4b")
+    run = serve(cfg, n_requests=4, batch=2, prompt_len=8, new_tokens=3,
+                max_len=32)
+    assert [len(r.tokens_out) for r in run.requests] == [3] * 4
+    assert all(0 <= t < cfg.vocab_size
+               for r in run.requests for t in r.tokens_out)
+    assert run.metrics["tokens"] == 12 and run.compile_s > 0
+    assert all(r.ttft <= r.latency for r in run.requests)
+    assert any(line.startswith("request 4:") for line in run.lines())
+    with pytest.raises(ValueError):
+        serve(cfg, n_requests=3, batch=2, prompt_len=8, max_len=32)
+
+
+def test_compile_cache_left_to_environment(monkeypatch, tmp_path):
+    from repro.launch.serve import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_chip_smoke_refuses_cpu():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
+                         env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
