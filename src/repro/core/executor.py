@@ -45,6 +45,7 @@ from repro.core.lease import (CLASS_PROTECTION, Lease, LeaseRequest,
                               LeaseState)
 from repro.core.perf_model import (DEFAULT_NET, NetParams, Sandbox, Tier,
                                    tier_overhead)
+from repro.core.tracing import span
 from repro.core.transport import (Channel, ChannelError, CONTROL_MSG_BYTES,
                                   Fabric, fabric_params_for_net)
 
@@ -223,13 +224,14 @@ class ExecutorWorker(threading.Thread):
                         f"function crashed executor {self.name}")
                 fn = self.library.by_index(inv.header.fn_index)
                 result = fn(inv.payload)
-                result = jax.block_until_ready(result)
-                exec_time = time.perf_counter() - t0
-                inv.timeline.exec_time = exec_time
-                inv.timeline.dispatch_measured = max(
-                    0.0, self.clock.now() - inv.timeline.t_submit
-                    - exec_time)
-                self._complete(inv, result, exec_time)
+                with span("exec.return", inv=inv.header.invocation_id):
+                    result = jax.block_until_ready(result)
+                    exec_time = time.perf_counter() - t0
+                    inv.timeline.exec_time = exec_time
+                    inv.timeline.dispatch_measured = max(
+                        0.0, self.clock.now() - inv.timeline.t_submit
+                        - exec_time)
+                    self._complete(inv, result, exec_time)
             except BaseException as e:  # noqa: BLE001 — forwarded to client
                 exec_time = time.perf_counter() - t0
                 self.on_done(self, inv, exec_time, e)

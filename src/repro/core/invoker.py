@@ -38,6 +38,7 @@ from repro.core.functions import FunctionLibrary
 from repro.core.invocation import Invocation, InvocationHeader, RFuture
 from repro.core.lease import LEASE_CLASSES, LeaseRequest
 from repro.core.resource_manager import ResourceManager
+from repro.core.tracing import span
 from repro.core.transport import (Channel, ChannelDropped, ChannelError,
                                   ChannelPartitioned, CONTROL_MSG_BYTES,
                                   Fabric, WIRE_COUNTERS)
@@ -576,9 +577,21 @@ class Invoker:
     # ----------------------------------------------------------- invocation
     def submit(self, fn_name: str, payload: Any,
                worker_hint: Optional[int] = None) -> RFuture:
-        """Non-blocking submission -> RFuture (std::future analogue)."""
+        """Non-blocking submission -> RFuture (std::future analogue).
+        On the real clock the client's share of it, from the record
+        minted to the invocation on the executor's queue, is the span
+        ``invoke.submit``; the simulator's submissions carry none."""
+        if self.clock.virtual:
+            return self._submit(fn_name, payload, worker_hint)
+        with span("invoke.submit", fn=fn_name) as note:
+            return self._submit(fn_name, payload, worker_hint, note)
+
+    def _submit(self, fn_name: str, payload: Any,
+                worker_hint: Optional[int], note=None) -> RFuture:
         idx = self.library.index_of(fn_name)
         inv = Invocation.make(idx, fn_name, payload)
+        if note is not None:
+            note.set_metadata(inv=inv.header.invocation_id)
         self.stats.invocations += 1
         try:
             self._dispatch(inv, worker_hint)
@@ -729,7 +742,14 @@ class RetryingFuture:
         single TOTAL budget: the deadline is computed once, and every
         retry attempt waits only the remaining slice — a crash partway
         through never restarts the clock (total wait stays bounded by
-        ``timeout``, not ``(max_retries+1) × timeout``)."""
+        ``timeout``, not ``(max_retries+1) × timeout``).  On the real
+        clock the wait is the span ``invoke.wait``."""
+        if self._invoker.clock.virtual:
+            return self._get(timeout)
+        with span("invoke.wait", inv=self._cur.header.invocation_id):
+            return self._get(timeout)
+
+    def _get(self, timeout: Optional[float]) -> Any:
         clock = self._invoker.clock
         deadline = None if timeout is None else clock.now() + timeout
         while True:

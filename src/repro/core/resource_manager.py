@@ -24,6 +24,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from repro.core.clock import Clock, REAL_CLOCK, ScheduledCall
 from repro.core.executor import ExecutorManager
 from repro.core.perf_model import DEFAULT_NET, NetParams
+from repro.core.tracing import span
 from repro.core.transport import (Channel, ChannelDropped,
                                   ChannelPartitioned, CONTROL_MSG_BYTES,
                                   Fabric, HEARTBEAT_MSG_BYTES,
@@ -425,8 +426,9 @@ class ResourceManager:
 
         def loop():
             while not stop.wait(interval_s):
-                for r in self.replicas:
-                    r.sweep_heartbeats()
+                with span("rm.heartbeat_sweep"):
+                    for r in self.replicas:
+                        r.sweep_heartbeats()
         self._hb_thread = threading.Thread(target=loop, daemon=True)
         self._hb_thread.start()
 

@@ -81,12 +81,16 @@ class ServeRun:
     compile_s: float           # warm-up wave: prefill + decode compiles
     wall_s: float              # timed requests, first enqueue to last token
     requests: List[GenRequest]
-    metrics: dict
+    bill_invocations: int
+    bill_compute_s: float
     peak_bytes: Optional[int]
     server: ModelServer
 
+    @property
+    def tokens(self) -> int:
+        return sum(len(r.tokens_out) for r in self.requests)
+
     def lines(self) -> List[str]:
-        m = self.metrics
         out = [f"arch={self.arch} device={self.platform}:{self.device_kind}"
                f" x{self.n_devices}",
                f"init_s={self.init_s:.3f} compile_s={self.compile_s:.3f}"]
@@ -94,11 +98,11 @@ class ServeRun:
             out.append(f"request {r.request_id}: ttft_s={r.ttft:.4f} "
                        f"latency_s={r.latency:.4f} "
                        f"tokens={len(r.tokens_out)}")
-        out.append(f"served {m['requests']} requests / {m['tokens']} tokens"
-                   f" in {self.wall_s:.3f} s | "
-                   f"{m['throughput_tok_s']:.2f} tok/s | "
-                   f"p50 latency {m['p50_latency_s']:.4f} s | "
-                   f"p50 ttft {m['p50_ttft_s']:.4f} s")
+        out.append(f"served {len(self.requests)} requests / {self.tokens} "
+                   f"tokens in {self.wall_s:.3f} s | "
+                   f"{self.tokens / self.wall_s:.2f} tok/s")
+        out.append(f"bill: {self.bill_invocations} invocations, "
+                   f"{self.bill_compute_s:.3f} s compute")
         out.append(f"peak_bytes_in_use={self.peak_bytes}")
         return out
 
@@ -155,17 +159,14 @@ def serve(cfg: ArchConfig, *, n_requests: int = 8, batch: int = 4,
                     invoker.allocate(1)
         done = engine.run()
         wall_s = time.perf_counter() - t0
-        metrics = engine.metrics()
     finally:
         invoker.deallocate()
         rm.stop()
     bill = ledger.bill("serve")
-    metrics["bill_invocations"] = bill.invocations
-    metrics["bill_compute_s"] = bill.compute_seconds
     d0 = devices[0]
     return ServeRun(cfg.name, d0.platform, d0.device_kind, len(devices),
-                    init_s, compile_s, wall_s, done, metrics,
-                    peak_bytes(devices), server)
+                    init_s, compile_s, wall_s, done, bill.invocations,
+                    bill.compute_seconds, peak_bytes(devices), server)
 
 
 def parse_args(argv=None):
@@ -195,8 +196,6 @@ def main(argv=None) -> ServeRun:
                 churn=args.churn)
     for line in run.lines():
         print(line)
-    print(f"bill: {run.metrics['bill_invocations']} invocations, "
-          f"{run.metrics['bill_compute_s']:.3f} s compute")
     return run
 
 

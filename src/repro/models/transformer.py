@@ -319,20 +319,23 @@ class DecoderLM:
 
     def _layer(self, x, lp, win, theta, positions, cache_entry, length,
                mode):
+        # the scopes name the device's operations by model part (their
+        # op_name metadata: ``.../attention/...``, ``.../mlp/...``)
         cfg = self.cfg
         rs = C.residual_scale(cfg)
-        h = L.apply_norm(x, lp["ln1"], cfg)
-        if mode == "decode":
-            attn, new_cache = self._attention_decode(h, lp["attn"], win,
-                                                     theta, cache_entry,
-                                                     length)
-        else:
-            attn, new_cache = self._attention_full(h, lp["attn"], win, theta,
-                                                   positions, cache_entry,
-                                                   length)
+        with jax.named_scope("attention"):
+            h = L.apply_norm(x, lp["ln1"], cfg)
+            if mode == "decode":
+                attn, new_cache = self._attention_decode(
+                    h, lp["attn"], win, theta, cache_entry, length)
+            else:
+                attn, new_cache = self._attention_full(
+                    h, lp["attn"], win, theta, positions, cache_entry,
+                    length)
         x = x + attn * rs
-        h = L.apply_norm(x, lp["ln2"], cfg)
-        ffn, aux = self._ffn(h, lp["ffn"], mode)
+        with jax.named_scope("mlp"):
+            h = L.apply_norm(x, lp["ln2"], cfg)
+            ffn, aux = self._ffn(h, lp["ffn"], mode)
         x = x + ffn * rs
         return x, new_cache, aux
 
@@ -413,8 +416,10 @@ class DecoderLM:
                                 else patch_embeds.shape[1])
         x, cache, _ = self._run_layers(x, params, positions, cache, None,
                                        "prefill")
-        x = L.apply_norm(x, params["final_norm"], self.cfg)
-        logits = C.lm_logits(x[:, -1:], params["embed"], self.cfg, self.dist)
+        with jax.named_scope("head"):
+            x = L.apply_norm(x, params["final_norm"], self.cfg)
+            logits = C.lm_logits(x[:, -1:], params["embed"], self.cfg,
+                                 self.dist)
         return logits, cache, jnp.full((), x.shape[1], jnp.int32)
 
     def decode(self, params, cache, tokens, length):
@@ -422,8 +427,9 @@ class DecoderLM:
         x = self._embed_inputs(params, tokens)
         x, cache, _ = self._run_layers(x, params, None, cache, length,
                                        "decode")
-        x = L.apply_norm(x, params["final_norm"], self.cfg)
-        logits = C.lm_logits(x, params["embed"], self.cfg, self.dist)
+        with jax.named_scope("head"):
+            x = L.apply_norm(x, params["final_norm"], self.cfg)
+            logits = C.lm_logits(x, params["embed"], self.cfg, self.dist)
         return logits, cache, length + 1
 
     # -------------------------------------------------------------- caches

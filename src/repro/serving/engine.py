@@ -9,15 +9,17 @@ buffers make the decode step zero-copy on the executor.
 
 ``ServeEngine`` is the client: it leases workers through the Invoker,
 pushes the model function library, and drives wave-scheduled batched
-generation with per-request latency accounting and optional straggler
-backup requests for stateless functions.
+generation, stamping each request's enqueue, first token and end on its
+clock; ``backup_submit`` adds straggler backup requests for stateless
+functions.
 """
 from __future__ import annotations
 
 import itertools
 import threading
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ import numpy as np
 
 from repro.core import FunctionLibrary, Invoker
 from repro.core.clock import Clock
+from repro.core.tracing import span
 
 _session_ids = itertools.count(1)
 
@@ -49,16 +52,26 @@ class ModelServer:
             self._decode_fn = model.decode
 
     # ------------------------------------------------- executor functions
+    # Each step is four spans on the executor's thread (core/tracing.py):
+    # ``.input`` the tokens' copy to the device, ``.dispatch`` the jitted
+    # step's call (it returns before the device is done), ``.sample`` the
+    # slice and argmax put behind it, ``.read`` the wait for the device
+    # and the copy of the next token to the host.
     def prefill(self, payload: dict) -> dict:
         """payload: {"tokens": (b, s) int}.  Creates a resident session;
         the cache NEVER travels back to the client (zero-copy residency)."""
-        tokens = jnp.asarray(payload["tokens"])
-        logits, cache, length = self._prefill_fn(self.params, tokens)
         sid = next(_session_ids)
+        with span("exec.prefill.input", sid=sid,
+                  rows=len(payload["tokens"])):
+            tokens = jnp.asarray(payload["tokens"])
+        with span("exec.prefill.dispatch", sid=sid):
+            logits, cache, length = self._prefill_fn(self.params, tokens)
         with self._lock:
             self._sessions[sid] = (cache, length)
-        next_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                              np.int32)
+        with span("exec.prefill.sample", sid=sid):
+            next_tok = jnp.argmax(logits[:, -1], axis=-1)
+        with span("exec.prefill.read", sid=sid):
+            next_tok = np.asarray(next_tok, np.int32)
         return {"sid": sid, "next_token": next_tok}
 
     def decode(self, payload: dict) -> dict:
@@ -67,10 +80,13 @@ class ModelServer:
         sid = int(payload["sid"])
         with self._lock:
             cache, length = self._sessions.pop(sid)
-        tokens = jnp.asarray(payload["tokens"])
+        with span("exec.decode.input", sid=sid,
+                  rows=len(payload["tokens"])):
+            tokens = jnp.asarray(payload["tokens"])
         try:
-            logits, new_cache, length = self._decode_fn(self.params, cache,
-                                                        tokens, length)
+            with span("exec.decode.dispatch", sid=sid):
+                logits, new_cache, length = self._decode_fn(
+                    self.params, cache, tokens, length)
         except BaseException:
             # a step that fails to trace, compile or dispatch has not yet
             # consumed the donated cache: put the session back, so the
@@ -83,7 +99,10 @@ class ModelServer:
             raise
         with self._lock:
             self._sessions[sid] = (new_cache, length)
-        next_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+        with span("exec.decode.sample", sid=sid):
+            next_tok = jnp.argmax(logits[:, -1], axis=-1)
+        with span("exec.decode.read", sid=sid):
+            next_tok = np.asarray(next_tok, np.int32)
         return {"sid": sid, "next_token": next_tok}
 
     def close_session(self, payload: dict) -> dict:
@@ -130,7 +149,9 @@ class ServeEngine:
         # default to the invoker's clock: request timestamps must live
         # on the same timeline the invocations complete on
         self.clock = invoker.clock if clock is None else clock
-        self._queue: List[GenRequest] = []
+        # enqueue() may run on another thread than run(): deque's append
+        # and popleft are atomic, and run() is the queue's one consumer
+        self._queue: Deque[GenRequest] = deque()
         self._rid = itertools.count(1)
         self.completed: List[GenRequest] = []
 
@@ -141,11 +162,12 @@ class ServeEngine:
         return req
 
     def run(self) -> List[GenRequest]:
-        """Drain the queue in waves of ``batch_size``."""
-        while self._queue:
-            wave, self._queue = (self._queue[:self.batch_size],
-                                 self._queue[self.batch_size:])
-            self._run_wave(wave)
+        """Drain the queue in waves of ``batch_size``.  A request enqueued
+        by another thread meanwhile joins the next wave."""
+        q = self._queue
+        while q:
+            self._run_wave([q.popleft()
+                            for _ in range(min(self.batch_size, len(q)))])
         return self.completed
 
     def _run_wave(self, wave: List[GenRequest]):
@@ -180,28 +202,6 @@ class ServeEngine:
                 r.t_done = now
         self.invoker.invoke("close_session", {"sid": sid})
         self.completed.extend(wave)
-
-    # ------------------------------------------------------------ metrics
-    def metrics(self) -> dict:
-        lats = [r.latency for r in self.completed if r.latency is not None]
-        ttfts = [r.ttft for r in self.completed if r.ttft is not None]
-        toks = sum(len(r.tokens_out) for r in self.completed)
-        span = (max(r.t_done for r in self.completed)
-                - min(r.t_enqueue for r in self.completed)
-                if self.completed else 0.0)
-        wire = self.invoker.transport_stats()    # DESIGN.md §12
-        return {
-            "requests": len(self.completed),
-            "tokens": toks,
-            "throughput_tok_s": toks / span if span else 0.0,
-            "p50_latency_s": float(np.median(lats)) if lats else 0.0,
-            "p99_latency_s": float(np.percentile(lats, 99)) if lats else 0.0,
-            "p50_ttft_s": float(np.median(ttfts)) if ttfts else 0.0,
-            # wire activity of the serving session: tokens ship as
-            # channel messages, so cost-per-token is auditable
-            "net_messages": wire["messages"],
-            "net_bytes": wire["bytes"],
-        }
 
 
 def backup_submit(invoker: Invoker, fn_name: str, payload,

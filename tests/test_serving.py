@@ -46,8 +46,8 @@ def test_batched_generation_completes():
         assert len(r.tokens_out) == 4
         assert r.latency is not None and r.latency > 0
         assert r.ttft is not None and r.ttft <= r.latency
-    m = engine.metrics()
-    assert m["tokens"] == 28 and m["throughput_tok_s"] > 0
+    assert sum(len(r.tokens_out) for r in done) == 28
+    assert max(r.t_done for r in done) > min(r.t_enqueue for r in done)
     assert ledger.bill("serve").invocations > 0
     inv.deallocate()
 
@@ -193,7 +193,8 @@ def test_serve_launcher_smoke():
     assert [len(r.tokens_out) for r in run.requests] == [3] * 4
     assert all(0 <= t < cfg.vocab_size
                for r in run.requests for t in r.tokens_out)
-    assert run.metrics["tokens"] == 12 and run.compile_s > 0
+    assert run.tokens == 12 and run.compile_s > 0
+    assert run.bill_invocations > 0
     assert all(r.ttft <= r.latency for r in run.requests)
     assert any(line.startswith("request 4:") for line in run.lines())
     with pytest.raises(ValueError):
