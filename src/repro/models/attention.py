@@ -11,8 +11,10 @@ Design notes (see DESIGN.md §5):
   * decode attends over a KV cache whose seq dim is sharded over `model`
     (flash-decoding layout); softmax reductions over the sharded axis lower
     to small all-reduces under GSPMD.
-  * KV heads are computed replicated and repeated to n_heads before the
-    core (GQA repeat is a free slice under head-sharded TP; see DESIGN.md).
+  * KV heads are computed replicated.  Train/prefill repeat them to
+    n_heads before the core (a free slice under head-sharded TP; see
+    DESIGN.md); decode contracts each query-head group against its shared
+    KV head, so the cache is read at its own head count and dtype.
 """
 from __future__ import annotations
 
@@ -180,27 +182,31 @@ def sliding_window_attention(q, k, v, *, window, softcap=0.0, block_q=512):
 
 
 def decode_attention(q, k_cache, v_cache, length, *, window=0, softcap=0.0):
-    """Single-step decode. q (b,1,h,hd); caches (b,S,h,hd) — seq dim may be
-    sharded over `model`; GSPMD turns the softmax/contraction reductions
-    into small all-reduces.  ``length`` = number of valid cache entries
-    (new token already written at length-1)."""
-    b, _, h, hd = q.shape
-    S = k_cache.shape[1]
-    scale = 1.0 / np.sqrt(hd)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
-                   k_cache.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    """Single-step decode. q (b,1,nq,hd); caches (b,S,nkv,hd) at their own
+    head count and dtype, nkv dividing nq — seq dim may be sharded over
+    `model`; GSPMD turns the softmax/contraction reductions into small
+    all-reduces.  ``length`` = number of valid cache entries (new token
+    already written at length-1).
+
+    Each group of g = nq // nkv query heads contracts against its shared
+    KV head, so the cache is read once, as it is stored.  Scores, softmax
+    and P·V accumulate in f32; p stays f32."""
+    b, _, nq, hd = q.shape
+    S, nkv = k_cache.shape[1], k_cache.shape[2]
+    qg = q.reshape(b, 1, nkv, nq // nkv, hd)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache,
+                   preferred_element_type=jnp.float32) * (1.0 / np.sqrt(hd))
     s = _softcap(s, softcap)
     pos = jnp.arange(S)
     mask = pos[None, :] < length
     if not (isinstance(window, int) and window == 0):
         w = jnp.asarray(window)
         mask &= jnp.where(w > 0, pos[None, :] >= length - w, True)
-    s = jnp.where(mask[None, None], s, NEG_INF)
+    s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v_cache.astype(jnp.float32),
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v_cache,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return out.reshape(b, 1, nq, hd).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

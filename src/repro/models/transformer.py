@@ -305,9 +305,7 @@ class DecoderLM:
                                       softcap=cfg.attn_logit_softcap,
                                       n_heads=cfg.n_heads)
         else:
-            kk = A.repeat_kv(k_c, cfg.n_heads)
-            vv = A.repeat_kv(v_c, cfg.n_heads)
-            o = A.decode_attention(q, kk, vv, n_valid, window=win,
+            o = A.decode_attention(q, k_c, v_c, n_valid, window=win,
                                    softcap=cfg.attn_logit_softcap)
         out = o.reshape(x.shape[0], 1, -1) @ ap["wo"]
         return dist.wsc(out, dp, None, None), {"k": k_c, "v": v_c}

@@ -68,11 +68,14 @@ def test_selective_scan_compiles_at_jamba_width(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_h2o_danube_decode_fits_one_chip(one_chip):
-    """The served decode step at full width (batch 4, max_len 2048):
-    weights, donated KV cache and temporaries within 16 GiB."""
-    model = build_model(get_config("h2o-danube-3-4b"))
-    batch, max_len = 4, 2048
+@pytest.mark.parametrize("batch,max_len", [(4, 2048), (8, 4096)])
+def test_h2o_danube_decode_fits_one_chip(one_chip, batch, max_len):
+    """The served decode step at full width: weights, donated KV cache and
+    temporaries within 16 GiB.  At the benchmark cells' shape (batch 8,
+    max_len 4096) the step also holds no float32 copy of the cache, at the
+    KV heads' count or repeated to the query heads'."""
+    cfg = get_config("h2o-danube-3-4b")
+    model = build_model(cfg)
     place = lambda tree: jax.tree.map(
         lambda s: on_chip(one_chip, s.shape, s.dtype), tree)
     params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
@@ -85,3 +88,9 @@ def test_h2o_danube_decode_fits_one_chip(one_chip):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 7.5e9       # the whole model is there
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
+    if (batch, max_len) == (8, 4096):
+        hd = cfg.resolved_head_dim
+        text = compiled.as_text()
+        for heads in (cfg.n_heads, cfg.n_kv_heads):
+            assert f"f32[{batch},{max_len},{heads},{hd}]" not in text
+        assert mem.temp_size_in_bytes < 3.5e9, mem.temp_size_in_bytes
