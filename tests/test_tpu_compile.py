@@ -3,9 +3,10 @@
 The chip's compiler is installed with jax and refuses what interpret mode
 accepts: Pallas blocks that are not tile-aligned, unaligned dynamic loads,
 programs that do not fit the device's memory.  These tests keep the main
-path's kernels at their real widths, and the served decode step of
-h2o-danube-3-4b at full width, inside what the chip accepts.  Nothing
-runs.
+path's kernels at their real widths, the served decode step of
+h2o-danube-3-4b at full width, and mistral-nemo-12b's tensor-parallel
+decode step over a described four-chip host, inside what the chip
+accepts.  Nothing runs.
 
 Only this file describes the topology, and only inside the fixture below:
 one process at a time may load the TPU's library, so describing it at
@@ -15,12 +16,20 @@ from __future__ import annotations
 
 import os
 
+from collections import Counter
+
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from chipbench import collectives, harness
+from chipbench import HERE as CHIPBENCH
 from repro.configs import get_config
+from repro.distribution.context import make_context
+from repro.distribution.sharding import param_shardings
+from repro.launch.mesh import make_mesh
 from repro.kernels.mamba_scan.kernel import selective_scan_pallas
 from repro.kernels.rwkv6.kernel import wkv6_pallas
 from repro.models.factory import build_model
@@ -30,15 +39,26 @@ HBM_BYTES = 16 * 1024 ** 3          # one TPU v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:                      # noqa: BLE001 — skip reason
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The (1, 4) ("data", "model") mesh of the tensor-parallel cell, on
+    the four chips of the described host."""
+    return make_mesh((1, 4), ("data", "model"), devices=topo.devices)
 
 
 def on_chip(sharding, shape, dtype=jnp.bfloat16):
@@ -94,3 +114,52 @@ def test_h2o_danube_decode_fits_one_chip(one_chip, batch, max_len):
         for heads in (cfg.n_heads, cfg.n_kv_heads):
             assert f"f32[{batch},{max_len},{heads},{hd}]" not in text
         assert mem.temp_size_in_bytes < 3.5e9, mem.temp_size_in_bytes
+
+
+def test_nemo_tp4_decode_fits_four_chips(four_chips):
+    """The tensor-parallel cell's served decode step (mistral-nemo-12b at
+    published widths, batch 8, max_len 4096) as the harness places it:
+    parameters by ``param_shardings``, the cache by ``cache_specs``.  Per
+    chip it fits in 16 GiB; the K/V cache stays split over the chips
+    (no array of one layer's whole cache); and the collectives of the
+    layer loop's body are pinned, so that a change of layout updates this
+    test on purpose."""
+    conf = harness.load_json(os.path.join(
+        CHIPBENCH, "configs", "mistral-nemo-12b-tp4.json"))
+    cfg = harness.arch_config(conf)
+    batch, max_len = conf["batch"], conf["max_len"]
+    mesh = four_chips
+    model = build_model(cfg, make_context(mesh))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, param_shardings(model, shapes))
+    specs = model.cache_specs()
+    cache = {k: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                     sharding=NamedSharding(mesh, specs[k]))
+             for k, s in jax.eval_shape(
+                 lambda: model.init_cache(batch, max_len)).items()}
+    whole = NamedSharding(mesh, P())
+    server = ModelServer(model, params, max_len=max_len)
+    compiled = server._decode_fn.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes > 6e9    # a quarter of 24.5 GB, and more
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
+    hd = cfg.resolved_head_dim
+    assert f"bf16[{batch},{max_len},{cfg.n_kv_heads},{hd}]" \
+        not in compiled.as_text()
+    (module,) = compiled.runtime_executable().hlo_modules()
+    proto = module.as_serialized_hlo_module_proto()
+    ops = collectives.collective_ops(proto)
+    entry = collectives.entry_computation(proto)
+    body = Counter(k for c, _, k in ops if c != entry)
+    # the layer loop's body: q gathered over the sequence shards (an async
+    # start, a fusion and a done), the softmax merged across them (three
+    # all-reduces), the row-parallel sums after wo and down (two); outside
+    # it the vocab-sharded embedding's one all-reduce
+    assert body == {"all-gather": 3, "all-reduce": 5}, ops
+    assert len(ops) - sum(body.values()) == 1, ops
