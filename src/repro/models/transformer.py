@@ -5,8 +5,11 @@ Layer heterogeneity that only changes *numbers* (gemma3 local/global window
 + rope theta) rides along as per-layer scalar xs; heterogeneity that changes
 *structure* (Jamba) lives in hybrid.py instead.
 
-KV caches are scan xs/ys with layout (L, b, S, h, hd) sharded
+The KV cache is one stacked array per leaf, layout (L, b, S, h, hd) sharded
 (None, dp, `model`, None, None) — the flash-decoding layout (DESIGN.md §5).
+Prefill and decode carry it through the layer scan: each layer writes only
+its new rows into it, in place, and decode attention reads the layer's
+slice of the carry; training has no cache.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax import shard_map
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from repro.models import attention as A
@@ -42,6 +46,23 @@ def layer_scalars(cfg):
         elif cfg.sliding_window:
             win[l] = cfg.sliding_window
     return jnp.asarray(win), jnp.asarray(theta)
+
+
+def _as_stored(update, shape, device):
+    """Give a cache write's ``update`` the layout that the cache of
+    ``shape`` is stored in on ``device`` between programs (the layout a
+    jitted program's arguments and results take).  A K/V row comes out of
+    a matrix product in the product's layout; left free, the compiler may
+    instead give the loop-carried cache the row's layout, and copy the
+    whole cache into and out of the layer loop on every step.  A TPU
+    stores a cache whose head size is not a multiple of 128 with its
+    sequence dim minor.  ``device`` None asks no layout."""
+    if device is None:
+        return update
+    layout = device.client.get_default_layout(update.dtype, tuple(shape),
+                                               device)
+    order = tuple(Layout.from_pjrt_layout(layout).major_to_minor)
+    return with_layout_constraint(update, Layout(order))
 
 
 class DecoderLM:
@@ -71,6 +92,10 @@ class DecoderLM:
         self.moe_full_ep = False      # experts over (data x model)
         self.no_fsdp_experts = False  # serving: replicate experts on data
         self.remat_policy = None      # None | "dots" (checkpoint policy)
+        # off a mesh, the device that holds the cache between steps, whose
+        # stored layout the cache writes take (serving.ModelServer sets it
+        # from the weights' device); None asks no layout of them
+        self.cache_device = None
 
     def full_ep_available(self):
         cfg, dist = self.cfg, self.dist
@@ -207,41 +232,96 @@ class DecoderLM:
         h = "model" if self.shard_heads else None
         return dp, h
 
-    def _attention_full(self, x, ap, win, theta, positions, cache_entry,
-                        length):
-        """Train/prefill attention. cache_entry None (train) or dict to
-        fill (prefill). Returns (out, new_cache_entry)."""
+    def _layer_cache(self, cache, layer):
+        """One layer's entry of a stacked cache (``layer`` None: ``cache``
+        already is one layer's)."""
+        if layer is None:
+            return cache
+        return jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, False), cache)
+
+    def _cache_device(self):
+        """A device that holds the cache: the mesh's, else
+        ``cache_device``."""
+        if self.dist.active:
+            return self.dist.mesh.devices.flat[0]
+        return self.cache_device
+
+    def _write_rows(self, cache, rows, at, layer=None):
+        """Write ``rows`` (leaves (b, T, ...)) at sequence position ``at``
+        of the cache: of layer ``layer`` of a stacked cache (leaves
+        (L, b, S, ...)), or of a one-layer cache (leaves (b, S, ...)) when
+        ``layer`` is None.  In place: no other slot is read or moved.
+
+        Where the cache's sequence dim is split over the mesh, each chip
+        writes the positions it holds, at a local offset, and leaves the
+        others' untouched; a dynamic offset on a split dim would otherwise
+        make the partitioner rewrite or gather whole shards."""
+        lead = () if layer is None else (layer,)
+        dist = self.dist
+        kv = dist.kv_axes()
+        device = self._cache_device()
+        if not dist.active or kv is None:
+            def put(c, r):
+                index = lead + (0, at) + (0,) * (r.ndim - 2)
+                r = r.astype(c.dtype).reshape((1,) * len(lead) + r.shape)
+                return jax.lax.dynamic_update_slice(
+                    c, _as_stored(r, c.shape, device), index)
+            return jax.tree.map(put, cache, rows)
+
+        mesh, axes = dist.mesh, dist.kv_seq
+        dp = dist.batch_axes()
+        pre = (None,) * len(lead)
+
+        def local(c, r, at, *lead):
+            shard = jnp.int32(0)
+            for a in axes:
+                shard = shard * mesh.shape[a] + jax.lax.axis_index(a)
+            S_l, T = c.shape[len(lead) + 1], r.shape[1]
+            n = min(T, S_l)
+            start = shard * S_l
+            off = jnp.clip(at - start, 0, S_l - n)
+            src = start + off + jnp.arange(n) - at     # rows' own indices
+            own = (src >= 0) & (src < T)
+            new = jnp.take(r, jnp.clip(src, 0, T - 1), axis=1)
+            new = new.astype(c.dtype)
+            index = lead + (0, off) + (0,) * (r.ndim - 2)
+            size = (1,) * len(lead) + new.shape
+            old = jax.lax.dynamic_slice(c, index, size).reshape(new.shape)
+            own = own.reshape((1, n) + (1,) * (r.ndim - 2))
+            new = jnp.where(own, new, old).reshape(size)
+            return jax.lax.dynamic_update_slice(
+                c, _as_stored(new, c.shape, device), index)
+
+        def put(c, r):
+            tail = (None,) * (r.ndim - 2)
+            return shard_map(
+                local, mesh=mesh,
+                in_specs=(P(*pre, dp, kv, *tail), P(dp, None, *tail), P())
+                + (P(),) * len(lead),
+                out_specs=P(*pre, dp, kv, *tail),
+                check_vma=False)(c, r, jnp.asarray(at, jnp.int32), *lead)
+        return jax.tree.map(put, cache, rows)
+
+    def _attention_full(self, x, ap, win, theta, positions, cache, length,
+                        layer=None):
+        """Train/prefill attention. ``cache`` None (train), or the cache
+        that prefill writes the prompt's K/V into at position 0 (of layer
+        ``layer`` when stacked). Returns (out, cache)."""
         cfg, dist = self.cfg, self.dist
         dp, hshard = self._attn_specs()
-        kv = dist.kv_axes()
         if cfg.mla is not None:
             out, (c_kv, k_rope) = A.mla_prefill(x, ap, cfg, positions)
-            new_cache = None
-            if cache_entry is not None:
-                S = cache_entry["ckv"].shape[1]
-                pad = S - c_kv.shape[1]
-                new_cache = {
-                    "ckv": dist.wsc(jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0))),
-                                    dp, kv, None),
-                    "krope": dist.wsc(
-                        jnp.pad(k_rope, ((0, 0), (0, pad), (0, 0))),
-                        dp, kv, None),
-                }
-            return out, new_cache
+            if cache is not None:
+                cache = self._write_rows(
+                    cache, {"ckv": c_kv, "krope": k_rope}, 0, layer)
+            return out, cache
         q, k, v = A.project_qkv(x, ap, cfg)
         if not cfg.no_rope:
             q = L.apply_rope(q, positions, theta)
             k = L.apply_rope(k, positions, theta)
-        new_cache = None
-        if cache_entry is not None:
-            S = cache_entry["k"].shape[1]
-            pad = S - k.shape[1]
-            new_cache = {
-                "k": dist.wsc(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                              dp, kv, None, None),
-                "v": dist.wsc(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                              dp, kv, None, None),
-            }
+        if cache is not None:
+            cache = self._write_rows(cache, {"k": k, "v": v}, 0, layer)
         k = A.repeat_kv(k, cfg.n_heads)
         v = A.repeat_kv(v, cfg.n_heads)
         q = dist.wsc(q, dp, None, hshard, None)
@@ -256,33 +336,37 @@ class DecoderLM:
         b, s = x.shape[:2]
         o = o.reshape(b, s, -1)
         out = dist.wsc(o @ ap["wo"], dp, None, None)
-        return out, new_cache
+        return out, cache
 
-    def _attention_decode(self, x, ap, win, theta, cache_entry, length):
+    def _attention_decode(self, x, ap, win, theta, cache, length,
+                          layer=None):
+        """One decode step's attention: the new token's K/V rows are
+        written into ``cache`` (layer ``layer`` of a stacked cache, or a
+        one-layer cache when None), then attention reads that layer's
+        entry.  Returns (out, cache)."""
         cfg, dist = self.cfg, self.dist
         dp = dist.batch_axes()
         kv = dist.kv_axes()
         positions = jnp.full((x.shape[0], 1), length, jnp.int32)
         if cfg.mla is not None:
             c_kv, k_rope = A.mla_latents(x, ap, cfg, positions)
-            ckv_c = jax.lax.dynamic_update_slice(
-                cache_entry["ckv"], c_kv, (0, length, 0))
-            krope_c = jax.lax.dynamic_update_slice(
-                cache_entry["krope"], k_rope, (0, length, 0))
-            ckv_c = dist.wsc(ckv_c, dp, kv, None)
-            krope_c = dist.wsc(krope_c, dp, kv, None)
+            cache = self._write_rows(
+                cache, {"ckv": c_kv, "krope": k_rope}, length, layer)
+            ce = self._layer_cache(cache, layer)
+            ckv_c = dist.wsc(ce["ckv"], dp, kv, None)
+            krope_c = dist.wsc(ce["krope"], dp, kv, None)
             if self.sp_decode and dist.active:
                 out = A.mla_decode_sp(x, ap, cfg, ckv_c, krope_c,
                                       length + 1, positions, dist)
             else:
                 out = A.mla_decode(x, ap, cfg, ckv_c, krope_c, length + 1,
                                    positions)
-            return out, {"ckv": ckv_c, "krope": krope_c}
+            return out, cache
         q, k, v = A.project_qkv(x, ap, cfg)
         if not cfg.no_rope:
             q = L.apply_rope(q, positions, theta)
             k = L.apply_rope(k, positions, theta)
-        S_cache = cache_entry["k"].shape[1]
+        S_cache = cache["k"].shape[-3]
         if self.window_cache:
             # ring buffer (perf iter, SWA long-context): slot = pos % W;
             # keys stored pre-rotated, so attention over slots is
@@ -293,12 +377,10 @@ class DecoderLM:
         else:
             write_at = length
             n_valid = length + 1
-        k_c = jax.lax.dynamic_update_slice(cache_entry["k"], k,
-                                           (0, write_at, 0, 0))
-        v_c = jax.lax.dynamic_update_slice(cache_entry["v"], v,
-                                           (0, write_at, 0, 0))
-        k_c = dist.wsc(k_c, dp, kv, None, None)
-        v_c = dist.wsc(v_c, dp, kv, None, None)
+        cache = self._write_rows(cache, {"k": k, "v": v}, write_at, layer)
+        ce = self._layer_cache(cache, layer)
+        k_c = dist.wsc(ce["k"], dp, kv, None, None)
+        v_c = dist.wsc(ce["v"], dp, kv, None, None)
         if self.sp_decode and dist.active:
             o = A.decode_attention_sp(q, k_c, v_c, n_valid, dist,
                                       window=win,
@@ -308,15 +390,15 @@ class DecoderLM:
             o = A.decode_attention(q, k_c, v_c, n_valid, window=win,
                                    softcap=cfg.attn_logit_softcap)
         out = o.reshape(x.shape[0], 1, -1) @ ap["wo"]
-        return dist.wsc(out, dp, None, None), {"k": k_c, "v": v_c}
+        return dist.wsc(out, dp, None, None), cache
 
     def _ffn(self, x, fp, mode="train"):
         if self.cfg.moe is not None:
             return self._moe(x, fp, mode)
         return L.apply_mlp(x, fp, self.cfg.act), jnp.float32(0.0)
 
-    def _layer(self, x, lp, win, theta, positions, cache_entry, length,
-               mode):
+    def _layer(self, x, lp, win, theta, positions, cache, length, mode,
+               layer=None):
         # the scopes name the device's operations by model part (their
         # op_name metadata: ``.../attention/...``, ``.../mlp/...``)
         cfg = self.cfg
@@ -324,41 +406,48 @@ class DecoderLM:
         with jax.named_scope("attention"):
             h = L.apply_norm(x, lp["ln1"], cfg)
             if mode == "decode":
-                attn, new_cache = self._attention_decode(
-                    h, lp["attn"], win, theta, cache_entry, length)
+                attn, cache = self._attention_decode(
+                    h, lp["attn"], win, theta, cache, length, layer)
             else:
-                attn, new_cache = self._attention_full(
-                    h, lp["attn"], win, theta, positions, cache_entry,
-                    length)
+                attn, cache = self._attention_full(
+                    h, lp["attn"], win, theta, positions, cache, length,
+                    layer)
         x = x + attn * rs
         with jax.named_scope("mlp"):
             h = L.apply_norm(x, lp["ln2"], cfg)
             ffn, aux = self._ffn(h, lp["ffn"], mode)
         x = x + ffn * rs
-        return x, new_cache, aux
+        return x, cache, aux
 
     # ------------------------------------------------------------- forwards
 
     def _run_layers(self, x, params, positions, cache, length, mode,
                     remat=False):
+        """The layer scan.  Prefill and decode carry the stacked cache
+        (held at ``cache_specs`` on entry and exit, so that prefill's
+        output and decode's input and output share one sharding); each
+        layer writes its new rows into it in place.  Training passes and
+        carries none."""
         win, theta = layer_scalars(self.cfg)
+        if cache is not None:
+            cache = self._pin_cache(cache)
 
         def body(carry, xs):
-            h = carry
-            lp, w, t, ce = xs
-            if mode == "train":
-                ce = None                      # placeholder xs, no cache
-            h, new_ce, aux = self._layer(h, lp, w, t, positions, ce, length,
-                                         mode)
-            return h, (new_ce, aux)
+            h, c = carry
+            lp, w, t, layer = xs
+            h, c, aux = self._layer(h, lp, w, t, positions, c, length, mode,
+                                    layer)
+            return (h, c), aux
 
         if remat:
             policy = (jax.checkpoint_policies.checkpoint_dots
                       if self.remat_policy == "dots" else None)
             body = jax.checkpoint(body, prevent_cse=False, policy=policy)
-        xs = (params["layers"], win, theta, cache)
-        x, (new_cache, aux) = jax.lax.scan(body, x, xs)
-        return x, new_cache, jnp.sum(aux)
+        xs = (params["layers"], win, theta, jnp.arange(self.cfg.n_layers))
+        (x, cache), aux = jax.lax.scan(body, (x, cache), xs)
+        if cache is not None:
+            cache = self._pin_cache(cache)
+        return x, cache, jnp.sum(aux)
 
     def _embed_inputs(self, params, tokens, patch_embeds=None):
         x = C.embed(tokens, params["embed"], self.cfg, self.dist)
@@ -374,7 +463,7 @@ class DecoderLM:
         x = self._embed_inputs(params, batch["tokens"], patches)
         positions = jnp.arange(x.shape[1])[None, :]
         x, _, aux = self._run_layers(x, params, positions,
-                                     self._null_cache(), None, "train",
+                                     None, None, "train",
                                      remat=True)
         x = L.apply_norm(x, params["final_norm"], cfg)
         if patches is not None:
@@ -432,8 +521,10 @@ class DecoderLM:
 
     # -------------------------------------------------------------- caches
 
-    def _null_cache(self):
-        return jnp.zeros((self.cfg.n_layers, 0), jnp.int32)
+    def _pin_cache(self, cache):
+        return jax.tree.map(lambda spec, c: self.dist.wsc(c, *spec),
+                            self.cache_specs(), cache,
+                            is_leaf=lambda x: isinstance(x, P))
 
     def cache_specs(self):
         """PartitionSpecs matching init_cache output."""

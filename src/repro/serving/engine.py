@@ -42,6 +42,11 @@ class ModelServer:
         self.max_len = max_len
         self._sessions: Dict[int, tuple] = {}       # sid -> (cache, length)
         self._lock = threading.Lock()
+        if hasattr(model, "cache_device"):
+            # the cache lives where the weights do (on a mesh, any of its
+            # devices, which are alike)
+            model.cache_device = next(iter(
+                jax.tree.leaves(params)[0].sharding.device_set))
         if jit_steps:
             self._prefill_fn = jax.jit(
                 lambda p, t: model.prefill(p, t, self.max_len))

@@ -102,7 +102,7 @@ def test_decode_matches_prefill_dense():
         from repro.models import layers as L
         x = model._embed_inputs(p, t)
         pos = jnp.arange(x.shape[1])[None, :]
-        x, _, _ = model._run_layers(x, p, pos, model._null_cache(), None,
+        x, _, _ = model._run_layers(x, p, pos, None, None,
                                     "train")
         x = L.apply_norm(x, p["final_norm"], cfg)
         return C.lm_logits(x, p["embed"], cfg, model.dist)
